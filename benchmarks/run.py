@@ -9,6 +9,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks import drift, kernels_bench, scenarios, tables
+from repro.launch.serve import device_line, use_compile_cache
 
 ALL = {
     "policy_sweep": scenarios.policy_sweep,
@@ -34,7 +35,7 @@ ALL = {
 }
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None, choices=list(ALL))
     ap.add_argument("--bench-dir", default=None, metavar="DIR",
@@ -43,8 +44,11 @@ def main() -> None:
                     "latency per config) for every sweep that records them")
     args = ap.parse_args()
     names = args.only or list(ALL)
+    use_compile_cache()
+    print(f"# {device_line()}", file=sys.stderr)
 
     print("name,us_per_call,derived")
+    failed = []
     for name in names:
         t0 = time.time()
         scenarios.pop_bench_records(name)  # drop stale in-process records
@@ -52,6 +56,7 @@ def main() -> None:
             rows = ALL[name]()
         except Exception as e:  # noqa: BLE001 — report and continue the suite
             print(f"{name}/ERROR,0,{type(e).__name__}: {e}")
+            failed.append(name)
             continue
         for rname, us, derived in rows:
             print(f"{rname},{us:.1f},{derived}")
@@ -63,7 +68,10 @@ def main() -> None:
                 json.dump({"scenario": name, "records": recs}, f, indent=1)
             print(f"# {name}: {len(recs)} records -> {path}", file=sys.stderr)
         print(f"# {name} done in {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"# failed: {' '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -28,6 +28,7 @@ from repro.core import (anoncampus_like_network, build_gallery, build_model,
 from repro.core.features import FeatureParams, make_features
 from repro.core.simulate import restrict_network
 from repro.core.tracker import make_queries
+from repro.runtime.engine import trace_key
 
 
 # ---------------------------------------------------------------------------
@@ -915,18 +916,6 @@ def transport_sweep(scenarios=("duke",), n_queries=16, steps=600, shards=4,
 # consolidation tentpole's headline number.
 # ---------------------------------------------------------------------------
 
-def _churn_trace_key(trace):
-    """Canonical per-round tuple stream (mirrors ``tests/conftest.trace_key``
-    — inlined because benchmarks must stay importable without the test tree):
-    admissions (mask), the match decision, tie-break (gallery row index), raw
-    kernel score, the top-k candidate bands and the model epoch."""
-    return [(r["qid"], r["f_curr"], r["phase"], r["epoch"],
-             tuple(bool(x) for x in r["mask"]), bool(r["matched"]),
-             int(r["match_cam"]), float(r["match_val"]), int(r["match_idx"]),
-             tuple(r["topk"]))
-            for r in trace]
-
-
 def _drive_churn(sc, policy, pool, n_queries, steps, t0, *, wave_at,
                  shards=None, consolidate=True, guard_after=None):
     """Churn-capable drive loop: submits HALF the queries up front and the
@@ -1034,7 +1023,7 @@ def query_churn_sweep(n_levels=(8, 64, 256), steps=180, shards=8,
         eng_r, tr_r, lat_r, m_r, wall_r = _drive_churn(
             sc, policy, pool, N, steps, t0, wave_at=wave_at, shards=None,
             consolidate=False)
-        assert _churn_trace_key(tr_c) == _churn_trace_key(tr_r), \
+        assert trace_key(tr_c) == trace_key(tr_r), \
             f"N={N}: consolidated fleet trace diverged from the " \
             f"unconsolidated single engine"
         assert eng_c.admitted_steps == eng_r.admitted_steps

@@ -38,9 +38,7 @@ _BANNED_DTYPES = ("float64", "complex128")
 
 
 def _sub_jaxprs(params: dict):
-    import jax.core as jcore
-    ClosedJaxpr = jcore.ClosedJaxpr
-    Jaxpr = jcore.Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def _from(v):
         if isinstance(v, ClosedJaxpr):

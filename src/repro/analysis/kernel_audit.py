@@ -45,15 +45,23 @@ def capture_pallas_calls(records: list):
     real = pl.pallas_call
 
     def fake(kernel, **kw):
+        # a PrefetchScalarGridSpec carries grid + specs itself, and its
+        # leading scalar operands are extra arguments of every index map
+        gs = kw.get("grid_spec")
+        src = kw if gs is None else dict(grid=gs.grid, in_specs=gs.in_specs,
+                                         out_specs=gs.out_specs)
+        nsp = 0 if gs is None else getattr(gs, "num_scalar_prefetch", 0)
+
         def runner(*operands):
             records.append(dict(
                 kernel=getattr(getattr(kernel, "func", kernel), "__name__",
                                str(kernel)),
-                grid=kw.get("grid"),
-                in_specs=list(kw.get("in_specs") or []),
-                out_specs=kw.get("out_specs"),
+                grid=src.get("grid"),
+                in_specs=list(src.get("in_specs") or []),
+                out_specs=src.get("out_specs"),
                 out_shape=kw.get("out_shape"),
-                operand_shapes=[tuple(np.shape(o)) for o in operands],
+                operand_shapes=[tuple(np.shape(o)) for o in operands[nsp:]],
+                scalar_prefetch=[np.asarray(o) for o in operands[:nsp]],
             ))
             raise _Captured
         return runner
@@ -81,8 +89,33 @@ def _as_list(x):
     return list(x) if isinstance(x, (list, tuple)) else [x]
 
 
+def _untiled(spec) -> bool:
+    """Specs the (8, 128) VMEM tiling rule does not apply to: scalar memory
+    (SMEM) and operands left in HBM for manual DMA (ANY)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    space = getattr(spec, "memory_space", None)
+    return space is not None and space in (pltpu.SMEM, pl.ANY)
+
+
+def _tiling_violation(block, shape) -> str | None:
+    """TPU VMEM tiling rule: the last block dim is a multiple of 128 and the
+    second-to-last a multiple of 8, unless the dim spans the whole operand
+    axis.  Mosaic refuses any other block."""
+    for axis, unit in ((-1, 128), (-2, 8)):
+        if len(block) < -axis:
+            continue
+        blk = shape[axis] if block[axis] is None else block[axis]
+        if blk % unit and blk != shape[axis]:
+            return (f"block {tuple(block)} breaks the TPU (8, 128) tiling "
+                    f"rule on operand shape {shape}: dim {axis} is {blk}, "
+                    f"neither a multiple of {unit} nor the whole axis")
+    return None
+
+
 def check_record(rec: dict) -> list[Violation]:
-    """Prove every BlockSpec index map in bounds over the full grid."""
+    """Prove every BlockSpec index map in bounds over the full grid, and
+    every VMEM block shape legal under the TPU tiling rule."""
     out: list[Violation] = []
     where = f"<pallas:{rec['kernel']}>"
     grid = rec["grid"]
@@ -99,14 +132,20 @@ def check_record(rec: dict) -> list[Violation]:
     out_shapes = [tuple(s.shape) for s in _as_list(rec["out_shape"])]
     pairs = list(zip(rec["in_specs"], rec["operand_shapes"])) + \
         list(zip(_as_list(rec["out_specs"]), out_shapes))
+    prefetch = rec.get("scalar_prefetch", [])
     for argno, (spec, shape) in enumerate(pairs):
         block = getattr(spec, "block_shape", None)
         imap = getattr(spec, "index_map", None)
         if block is None or imap is None:
-            continue
+            continue              # whole-array spec: trivially in bounds
+        if not _untiled(spec):
+            msg = _tiling_violation(block, shape)
+            if msg:
+                out.append(Violation("PALLAS", where, 0,
+                                     f"arg {argno}: {msg}"))
         bad = 0
         for point in itertools.product(*map(range, grid)):
-            idx = imap(*point)
+            idx = imap(*point, *prefetch)
             idx = tuple(idx) if isinstance(idx, (tuple, list)) else (idx,)
             if len(idx) != len(block) or len(block) != len(shape):
                 out.append(Violation(

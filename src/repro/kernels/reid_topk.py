@@ -19,6 +19,10 @@ segment mask is enforced on-device (camera membership via a one-hot GEMM,
 MXU-friendly; no (Q, G) mask ever materializes in HBM).
 
 Grid (nq, ng): gallery axis innermost, top-k state carried in VMEM scratch.
+
+The score GEMM asks for ``Precision.HIGHEST``: at the default a v5e
+contracts f32 operands in one bf16 pass, which moves scores by ~1e-3 and
+reorders near-ties against the f32 oracle in ``kernels/ref.py``.
 """
 from __future__ import annotations
 
@@ -30,6 +34,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# byte budget for one buffer of the tile kernel's (CTp, block_g) f32
+# one-hot block: double-buffered, it keeps half of the 16 MiB scoped-VMEM
+# default, which leaves room for the (block_q, CTp) admission block
+_ONEHOT_BLOCK_BYTES = 4 << 20
 
 
 def _round_up(x: int, m: int) -> int:
@@ -52,12 +61,35 @@ def _blocks(dim: int, block: int, align: int):
 
 
 def _merge_topk(s, cols, val_scr, idx_scr, k: int):
-    """Fold one (block_q, block_g) score tile into the running VMEM top-k."""
-    merged_v = jnp.concatenate([val_scr[...], s], axis=1)
-    merged_i = jnp.concatenate([idx_scr[...], cols], axis=1)
-    top_v, pos = jax.lax.top_k(merged_v, k)
-    val_scr[...] = top_v
-    idx_scr[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    """Fold one (block_q, block_g) score tile into the running VMEM top-k.
+
+    Exactly a stable top-k over the lane concatenation [scratch, tile]:
+    values descend, and equal values go to the scratch entry first (lowest
+    slot), then to the lowest tile column.  Scratch entries carry lower
+    global columns than the tile, so ties resolve to the lowest global
+    column, and a NEG_INF tile never displaces a scratch slot.  Built from
+    k static passes of row max + lowest position among the maxima — the
+    reductions and selects Mosaic lowers (no ``top_k``, no gathers, no
+    unaligned lane concatenation)."""
+    sv, si = val_scr[...], idx_scr[...]                   # (block_q, k)
+    slot = jax.lax.broadcasted_iota(jnp.int32, sv.shape, 1)
+    out_v, out_i = sv, si
+    for j in range(k):
+        ms = jnp.max(sv, axis=1, keepdims=True)
+        mt = jnp.max(s, axis=1, keepdims=True)
+        from_scr = ms >= mt                               # ties: scratch first
+        ps = jnp.min(jnp.where(sv == ms, slot, k), axis=1, keepdims=True)
+        scr_idx = jnp.max(jnp.where(slot == ps, si, jnp.iinfo(jnp.int32).min),
+                          axis=1, keepdims=True)
+        pt = jnp.min(jnp.where(s == mt, cols, jnp.iinfo(jnp.int32).max),
+                     axis=1, keepdims=True)
+        out_v = jnp.where(slot == j, jnp.where(from_scr, ms, mt), out_v)
+        out_i = jnp.where(slot == j, jnp.where(from_scr, scr_idx, pt), out_i)
+        # retire the pick: -inf sits below every score and NEG_INF slot
+        sv = jnp.where(from_scr & (slot == ps), -jnp.inf, sv)
+        s = jnp.where(~from_scr & (cols == pt), -jnp.inf, s)
+    val_scr[...] = out_v
+    idx_scr[...] = out_i
 
 
 def _reid_kernel(q_ref, g_ref, sv_ref, si_ref, val_scr, idx_scr, *,
@@ -72,6 +104,7 @@ def _reid_kernel(q_ref, g_ref, sv_ref, si_ref, val_scr, idx_scr, *,
     q = q_ref[...].astype(jnp.float32)                    # (block_q, D)
     g = g_ref[...].astype(jnp.float32)                    # (block_g, D)
     s = jax.lax.dot_general(q, g, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # (block_q, block_g)
     base = gi * block_g
     cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -149,6 +182,7 @@ def _reid_masked_kernel(q_ref, qf_ref, adm_ref, g_ref, gf_ref, oh_ref,
     q = q_ref[...].astype(jnp.float32)                    # (block_q, D)
     g = g_ref[...].astype(jnp.float32)                    # (block_g, D)
     s = jax.lax.dot_general(q, g, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # (block_q, block_g)
     # camera admission via one-hot GEMM: (block_q, C) @ (C, block_g) on the
     # MXU — avoids a lane-axis gather of admit[:, gal_cam]
@@ -272,18 +306,19 @@ def _reid_tiles_kernel(q_ref, qt_ref, adm_ref, g_ref, gt_ref, oh_ref,
     score the skipped block would contribute is NEG_INF, and ``_merge_topk``
     resolves NEG_INF ties in favor of the existing scratch entries — the
     scratch is bit-identical either way."""
-    gi = pl.program_id(1)
+    qi, gi = pl.program_id(0), pl.program_id(1)
 
     @pl.when(gi == 0)
     def _init():
         val_scr[...] = jnp.full_like(val_scr, NEG_INF)
         idx_scr[...] = jnp.full_like(idx_scr, -1)
 
-    @pl.when(live_ref[0, 0] > 0)
+    @pl.when(live_ref[qi * ng + gi] > 0)
     def _score():
         q = q_ref[...].astype(jnp.float32)                # (block_q, D)
         g = g_ref[...].astype(jnp.float32)                # (block_g, D)
         s = jax.lax.dot_general(q, g, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
         # (cam, tile) admission via one-hot GEMM over the fused axis —
         # same MXU shape as camera admission, just C*T*T columns
@@ -333,9 +368,14 @@ def reid_topk_tiles(queries, q_tag, admit_ct, gallery, gal_ct, gal_tag,
     CT = admit_ct.shape[1]
     if Q == 0 or G == 0:
         return _empty(Q, k)
+    CTp = _round_up(CT, 8)
+    # the (CTp, block_g) one-hot block is the kernel's largest VMEM buffer:
+    # narrow block_g as the fused-cell axis grows (130 cameras at T=8 ->
+    # 8,320 cells -> 128 lanes, the floor)
+    block_g = min(block_g, max(128, _ONEHOT_BLOCK_BYTES // (4 * CTp)
+                               // 128 * 128))
     block_q, Qp = _blocks(Q, block_q, 8)
     block_g, Gp = _blocks(G, block_g, 128)
-    CTp = _round_up(CT, 8)
     nq, ng = Qp // block_q, Gp // block_g
 
     queries = _pad_rows(queries, Qp, 0)
@@ -358,7 +398,7 @@ def reid_topk_tiles(queries, q_tag, admit_ct, gallery, gal_ct, gal_tag,
         q_any.astype(jnp.float32), g_has.astype(jnp.float32),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) > 0.0
-    block_live = block_live.astype(jnp.int32)             # (nq, ng)
+    block_live = block_live.astype(jnp.int32).reshape(nq * ng)
 
     kernel = functools.partial(_reid_tiles_kernel, k=k, block_g=block_g,
                                ng=ng, g_real=G)
@@ -372,7 +412,8 @@ def reid_topk_tiles(queries, q_tag, admit_ct, gallery, gal_ct, gal_tag,
             pl.BlockSpec((block_g, D), lambda qi, gi: (gi, 0)),
             pl.BlockSpec((1, block_g), lambda qi, gi: (0, gi)),
             pl.BlockSpec((CTp, block_g), lambda qi, gi: (0, gi)),
-            pl.BlockSpec((1, 1), lambda qi, gi: (qi, gi)),
+            # whole (nq * ng,) table in SMEM: scalar reads per grid step
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block_q, k), lambda qi, gi: (qi, 0)),

@@ -31,13 +31,10 @@ import traceback
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-
 from repro.configs import ARCH_IDS, get_config
 from repro.perf import PerfFlags, perf_flags
 from repro.launch.mesh import make_production_mesh
+from repro.launch.serve import use_compile_cache
 from repro.launch.shapes import SHAPES, cell_supported
 from repro.launch.steps import build_step
 from repro.parallel.sharding import (MULTI_POD_RULES, SINGLE_POD_RULES,
@@ -245,6 +242,7 @@ def main():
     ap.add_argument("--tag", default=None, help="artifact filename suffix")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.opt:
         flags = PerfFlags.all_on()

@@ -6,7 +6,13 @@ device state — required because the dry-run must set
 """
 from __future__ import annotations
 
-from repro.parallel.compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

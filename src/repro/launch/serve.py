@@ -31,11 +31,96 @@ trips the trigger (knobs: ``--drift-threshold``, ``--recal-cooldown``,
 from __future__ import annotations
 
 import argparse
+import os
 import time
+
+import jax
 
 from repro import api as rexcam
 from repro.core import build_gallery, duke_like_network, simulate_network
 from repro.core.features import FeatureParams, make_features
+from repro.core.simulate import tile_index
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed path, so every
+    process of this checkout reuses what an earlier one compiled.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
+    directory is set here; otherwise the cache lives at
+    ``<checkout>/.jax_cache``.  Either way every program is cached, however
+    fast it compiled: the serving steps compile in well under JAX's default
+    one-second floor, which would keep none of them (a
+    ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` in the environment still
+    wins).  Returns the directory in use."""
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_line() -> str:
+    """The device JAX runs on, as every run should report it."""
+    d = jax.devices()
+    return (f"device: platform={d[0].platform} kind={d[0].device_kind} "
+            f"count={len(d)}")
+
+
+def duke_world(n_queries: int, tile_grid: int = 0) -> dict:
+    """The simulated 8-camera Duke-like campus this CLI replays: 1,500
+    identities over a 3,000 s horizon, up to 24 detections per
+    camera-step, the model profiled on the first 2,000 s (``tile_grid=T``
+    also learns T x T entry-region masks), and ``n_queries`` query
+    sightings drawn with seed 1."""
+    net = duke_like_network()
+    vis = simulate_network(net, 1500, 3000, seed=0)
+    gal, _ = build_gallery(vis, 24)
+    model = rexcam.profile(vis, time_limit=2000, tile_grid=tile_grid)
+    feats, _ = make_features(vis, 1500, FeatureParams())
+    q_vids, _ = rexcam.make_queries(vis, n_queries, seed=1)
+    return dict(net=net, vis=vis, gal=gal, model=model, feats=feats,
+                q_vids=q_vids)
+
+
+def serve_world(world: dict, **serve_kw):
+    """Serve ``world`` (``duke_world``'s keys) through ``repro.api.serve``
+    with the identity embedder over its precomputed features, start the
+    clock at the earliest query sighting and submit every query there.
+    ``recalibrate=`` re-profiles from the world's own visits."""
+    vis, feats, q_vids = world["vis"], world["feats"], world["q_vids"]
+    if serve_kw.get("recalibrate"):
+        serve_kw["visit_source"] = rexcam.visits_window_source(vis)
+    eng = rexcam.serve(world["model"], embed_fn=lambda x: x,
+                       geo_adj=world["net"].geo_adjacent, **serve_kw)
+    eng.t = int(vis.t_out[q_vids].min())
+    for i, q in enumerate(q_vids):
+        eng.submit_query(i, feats[q], int(vis.cam[q]), int(vis.t_out[q]))
+    return eng
+
+
+def ingest_tick(eng, world: dict, t: int) -> None:
+    """Ingest step ``t`` of the world's live stream: every camera's
+    detections as feature rows, plus their sub-frame tile labels when the
+    engine serves a tile grid."""
+    vis, gal, feats = world["vis"], world["gal"], world["feats"]
+    frames, tiles = {}, {}
+    for c in range(vis.n_cams):
+        vids = gal[c, t]
+        vids = vids[vids >= 0]
+        if len(vids):
+            frames[c] = feats[vids]
+            if eng.tile_grid > 0:
+                tiles[c] = tile_index(vis.tile_xy[vids], eng.tile_grid)
+    if eng.tile_grid > 0:
+        eng.ingest(frames, tiles)
+    else:
+        eng.ingest(frames)
 
 
 def main():
@@ -99,14 +184,11 @@ def main():
     ap.add_argument("--recal-window", type=int, default=1200,
                     help="sliding re-profile window (recent steps)")
     args = ap.parse_args()
+    use_compile_cache()
+    print(device_line())
 
-    net = duke_like_network()
-    vis = simulate_network(net, 1500, 3000, seed=0)
-    gal, _ = build_gallery(vis, 24)
-    model = rexcam.profile(vis, time_limit=2000, tile_grid=args.tile_grid)
-    feats, _ = make_features(vis, 1500, FeatureParams())
-    q_vids, _ = rexcam.make_queries(vis, args.queries, seed=1)
-
+    world = duke_world(args.queries, args.tile_grid)
+    net, vis, q_vids = world["net"], world["vis"], world["q_vids"]
     policy = rexcam.SearchPolicy(scheme=args.scheme, s_thresh=args.s_thresh,
                                  t_thresh=args.t_thresh)
     recal = rexcam.RecalibrationPolicy(
@@ -119,39 +201,17 @@ def main():
             timeout=max(4 * (args.rtt + args.jitter), 1.0))
     else:
         transport = None if args.transport == "none" else args.transport
-    eng = rexcam.serve(model, embed_fn=lambda x: x, policy=policy,
-                       geo_adj=net.geo_adjacent, shards=args.shards,
-                       gallery=args.gallery, topk=args.topk,
-                       transport=transport, prefetch=args.prefetch,
-                       tile_grid=args.tile_grid,
-                       topk_rerank=args.topk_rerank,
-                       recalibrate=recal,
-                       visit_source=rexcam.visits_window_source(vis)
-                       if args.recalibrate else None)
-    t0 = int(vis.t_out[q_vids].min())
-    eng.t = t0
-    for i, q in enumerate(q_vids):
-        eng.submit_query(i, feats[q], int(vis.cam[q]), int(vis.t_out[q]))
+    eng = serve_world(world, policy=policy, shards=args.shards,
+                      gallery=args.gallery, topk=args.topk,
+                      transport=transport, prefetch=args.prefetch,
+                      tile_grid=args.tile_grid,
+                      topk_rerank=args.topk_rerank, recalibrate=recal)
+    t0 = eng.t
 
-    if args.tile_grid > 0:
-        from repro.core.simulate import tile_index
-        vis_tiles = tile_index(vis.tile_xy, args.tile_grid)
     wall0 = time.time()
     matches = 0
     for t in range(t0, min(t0 + args.steps, vis.horizon)):
-        frames = {}
-        tiles = {}
-        for c in range(net.n_cams):
-            vids = gal[c, t]
-            vids = vids[vids >= 0]
-            if len(vids):
-                frames[c] = feats[vids]
-                if args.tile_grid > 0:
-                    tiles[c] = vis_tiles[vids]
-        if args.tile_grid > 0:
-            eng.ingest(frames, tiles)
-        else:
-            eng.ingest(frames)
+        ingest_tick(eng, world, t)
         stats = eng.tick()
         matches += stats["matches"]
     wall = time.time() - wall0

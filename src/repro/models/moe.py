@@ -23,10 +23,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.layers import Params, _dtype, _pdtype, dense_init
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import constrain, get_mesh_context
 
 MOE_CHUNK = 8192          # tokens per dispatch chunk (per device)

@@ -172,7 +172,17 @@ def _rank_outcome(sv, si, gallery, gal_cam, gal_frame, match_thresh,
     unchanged (the bands are score-sorted, so "any band passes" == "band 0
     passes"), and at k=1 only band 0 can vote — the whole path is
     bit-identical to the argmax path, which the k=1 equivalence regression
-    pins."""
+    pins.
+
+    An empty gallery (G == 0) has nothing to gather from: every row comes
+    back unmatched with the (NEG_INF, -1) sentinel bands and a zero
+    embedding."""
+    if gallery.shape[0] == 0:
+        Q = sv.shape[0]
+        none = jnp.full(si.shape, -1, jnp.int32)
+        return (jnp.zeros(Q, bool), jnp.zeros(Q, jnp.int32),
+                jnp.zeros((Q, gallery.shape[1]), gallery.dtype), sv, si,
+                none, none)
     valid = si >= 0
     idx = jnp.maximum(si, 0)
     topk_cam = jnp.where(valid, gal_cam[idx], -1).astype(jnp.int32)
@@ -1198,3 +1208,16 @@ class ServingEngine:
             q.f_curr, q.phase, q.done = f_new, phase_new, bool(done)
             if q.done:
                 self._on_query_done(q)
+
+
+def trace_key(trace: list) -> list[tuple]:
+    """Canonical per-round tuple stream of ``tick(record_trace=...)``
+    records: admissions (mask), the match decision, tie-break (gallery row
+    index), raw kernel score, the top-k (value, cam, frame) candidate bands
+    and the model epoch the round ran under.  Two runs are trace-identical
+    when their keys are equal."""
+    return [(r["qid"], r["f_curr"], r["phase"], r["epoch"],
+             tuple(bool(x) for x in r["mask"]), bool(r["matched"]),
+             int(r["match_cam"]), float(r["match_val"]), int(r["match_idx"]),
+             tuple(r["topk"]))
+            for r in trace]

@@ -20,7 +20,7 @@ fleet while the tiny correlation model M stays replicated on every worker.
     per-shard re-embedding),
   * every device round runs the SAME step bodies as the single-process
     ``ServingEngine`` (``policy.admit``, ``engine.rank_advance_round``)
-    wrapped in ``parallel.compat.shard_map`` — so the fleet is
+    wrapped in ``jax.shard_map`` — so the fleet is
     trace-identical to one engine by construction, which the differential
     harness in ``tests/test_sharded_engine.py`` pins down.
 
@@ -46,10 +46,10 @@ from typing import Iterable
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.policy import admit, admit_tiles
-from repro.parallel.compat import shard_map
 from repro.runtime.cluster import ElasticMesh, HeartbeatMonitor
 from repro.runtime.engine import (EngineConfig, QueryState, RoundPlan,
                                   ServingEngine, _pow2, advance_round,
@@ -463,7 +463,8 @@ class ShardedServingEngine(ServingEngine):
         slice of the fleet-global dedup set; sums to the engine's
         ``unique_frames`` when the gallery is sharded)."""
         live = set(self._workers)
-        rows = [dict(worker=w, alive=w in live,
+        rows = [dict(worker=w, device=self._device_of[w].id,
+                     alive=w in live,
                      queries=self._load(w) if w in live else 0,
                      **self._shard_stats[w])
                 for w in self._all_workers]

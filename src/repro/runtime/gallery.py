@@ -364,18 +364,22 @@ class ShardedGalleryStore(GalleryStore):
 
     def per_worker_report(self) -> dict[str, dict]:
         """Owner-resident cache memory, per worker: cameras owned, resident
-        blocks/rows/bytes, plus the fetch plane's per-peer traffic when a
-        transport is attached.  Lost workers report zeros after ``rehome``."""
-        rep = {w: dict(cameras=0, blocks=0, rows=0, bytes=0,
+        blocks/rows/bytes, blocks ``misplaced`` off the owner's device
+        (always 0 unless placement is broken), plus the fetch plane's
+        per-peer traffic when a transport is attached.  Lost workers report
+        zeros after ``rehome``."""
+        rep = {w: dict(cameras=0, blocks=0, rows=0, bytes=0, misplaced=0,
                        remote_fetches=0, retries=0, timeouts=0)
                for w in self._device_of}
         for w in self._owner.values():
             rep[w]["cameras"] += 1
         for (cam, _t), (arr, n) in self._blocks.items():
-            r = rep[self._owner[cam]]
+            owner = self._owner[cam]
+            r = rep[owner]
             r["blocks"] += 1
             r["rows"] += n
             r["bytes"] += arr.nbytes
+            r["misplaced"] += arr.devices() != {self._device_of[owner]}
         if self.transport is not None:
             for w, st in self.transport.peer_counters().items():
                 if w in rep:

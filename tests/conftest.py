@@ -212,15 +212,10 @@ def drive_serving_trace(world, policy, *, shards=None, lose_at=None,
 
 
 def trace_key(trace):
-    """Canonical per-round tuple stream: admissions (mask), the match
-    decision, tie-break (gallery row index), raw kernel score, the
-    top-k (value, cam, frame) candidate bands and the model epoch the
-    round ran under (recalibration swap boundaries)."""
-    return [(r["qid"], r["f_curr"], r["phase"], r["epoch"],
-             tuple(bool(x) for x in r["mask"]), bool(r["matched"]),
-             int(r["match_cam"]), float(r["match_val"]), int(r["match_idx"]),
-             tuple(r["topk"]))
-            for r in trace]
+    """The engine's canonical per-round key (``engine.trace_key``): what
+    two runs must agree on to be trace-identical."""
+    from repro.runtime.engine import trace_key as key
+    return key(trace)
 
 
 def assert_fleet_trace_identical(world, policy, shards, *, lose_at=None,

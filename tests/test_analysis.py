@@ -131,8 +131,9 @@ def test_jaxpr_audit_flags_debug_callback():
         jax.debug.print("x={x}", x=x)
         return x * 2
 
+    # jax.debug.print traces to the `debug_print` host-callback primitive
     vs = audit_closed_jaxpr("noisy", noisy.trace(jnp.ones(3)).jaxpr)
-    assert any("debug_callback" in v.msg for v in vs)
+    assert any("forbidden primitive `debug_print`" in v.msg for v in vs)
 
 
 def test_jaxpr_audit_flags_weak_type_output():
@@ -157,7 +158,7 @@ def test_jaxpr_audit_flags_f64():
     def promote(x):
         return x.astype(jnp.float64) + 1
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         traced = promote.trace(jnp.ones(3, jnp.float32))
     vs = audit_closed_jaxpr("promote", traced.jaxpr)
     assert any("float64" in v.msg for v in vs)
@@ -241,14 +242,52 @@ def test_kernel_bounds_prover_flags_oob_index_map():
     from types import SimpleNamespace
     from repro.analysis.kernel_audit import check_record
 
-    spec = SimpleNamespace(block_shape=(8, 8), index_map=lambda i, j: (i, j))
+    spec = SimpleNamespace(block_shape=(8, 128),
+                           index_map=lambda i, j: (i, j))
     rec = dict(kernel="bad", grid=(3, 2), in_specs=[spec], out_specs=None,
-               out_shape=None, operand_shapes=[(16, 16)])
+               out_shape=None, operand_shapes=[(16, 256)])
     vs = check_record(rec)        # grid point (2, 0) reads rows 16..24
     assert len(vs) == 1 and "out of bounds" in vs[0].msg
 
-    rec["operand_shapes"] = [(24, 16)]
+    rec["operand_shapes"] = [(24, 256)]
     assert check_record(rec) == []
+
+
+def test_kernel_audit_flags_block_off_the_tpu_tiling():
+    """A (1, 1) VMEM block over an (nq, ng) table — what the tile kernel's
+    liveness input once was — is refused by Mosaic: the audit flags it on
+    CPU.  The same table as a whole-array SMEM spec, or read through a
+    scalar-prefetch index map, is legal."""
+    from types import SimpleNamespace
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    import jax
+    import jax.numpy as jnp
+    from repro.analysis.kernel_audit import _capture_call, check_record
+
+    spec = SimpleNamespace(block_shape=(1, 1), index_map=lambda i, j: (i, j))
+    rec = dict(kernel="live", grid=(2, 8), in_specs=[spec], out_specs=None,
+               out_shape=None, operand_shapes=[(2, 8)])
+    vs = check_record(rec)
+    assert len(vs) == 1 and "tiling rule" in vs[0].msg
+    rec["in_specs"] = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    assert check_record(rec) == []
+
+    def call(live, x):
+        return pl.pallas_call(
+            lambda *refs: None,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(2,),
+                in_specs=[pl.BlockSpec((8, 128),
+                                       lambda i, live: (live[i], 0))],
+                out_specs=pl.BlockSpec((8, 128), lambda i, live: (i, 0))),
+            out_shape=jax.ShapeDtypeStruct((16, 128), jnp.float32))(live, x)
+
+    x = np.zeros((16, 128), np.float32)
+    ok, = _capture_call(call, np.array([1, 0], np.int32), x)
+    assert check_record(ok) == []
+    oob, = _capture_call(call, np.array([0, 2], np.int32), x)
+    assert "out of bounds" in check_record(oob)[0].msg
 
 
 def test_kernel_capture_intercepts_without_execution():

@@ -73,6 +73,7 @@ def test_sharded_gallery_store_device_blocks_roundtrip():
     rep = g.per_worker_report()
     assert rep["w0"]["cameras"] == 3 and rep["w0"]["blocks"] == 1
     assert rep["w0"]["rows"] == 5 and rep["w0"]["bytes"] == arr.nbytes
+    assert rep["w0"]["misplaced"] == 0               # block on owner's device
     assert g.memory_bytes() == arr.nbytes
     with pytest.raises(RuntimeError):
         g.rehome("w0", [])                   # no survivors: fail loudly

@@ -54,21 +54,36 @@ def test_decode_attention_sweep(dtype, B, H, KV, T, hd, bk):
                                **_tol(dtype))
 
 
-@pytest.mark.parametrize("Q,G,D,k,bq,bg", [
+_SWEEP = [
     (64, 512, 64, 8, 32, 128),
     (128, 1024, 32, 16, 128, 256),
     (32, 256, 128, 4, 32, 64),
     (33, 517, 16, 5, 32, 128),      # ragged: internal padding both axes
     (7, 70, 8, 3, 128, 512),        # smaller than one block on both axes
     (1, 1, 64, 1, 128, 512),
+]
+
+
+@pytest.mark.parametrize("Q,G,D,k,bq,bg,ties", [
+    *[pytest.param(*c, False, id="-".join(map(str, c))) for c in _SWEEP],
+    # 0/1 features: exact float32 ties spread over all six gallery blocks,
+    # so the running merge must keep the oracle's lowest-column order
+    *[pytest.param(40, 700, 8, k, 16, 128, True, id=f"ties-k{k}")
+      for k in (1, 3, 5)],
 ])
-def test_reid_topk_sweep(Q, G, D, k, bq, bg):
+def test_reid_topk_sweep(Q, G, D, k, bq, bg, ties):
     ks = jax.random.split(KEY, 2)
-    q = jax.random.normal(ks[0], (Q, D))
-    g = jax.random.normal(ks[1], (G, D))
+    if ties:
+        q = jax.random.bernoulli(ks[0], 0.5, (Q, D)).astype(jnp.float32)
+        g = jax.random.bernoulli(ks[1], 0.5, (G, D)).astype(jnp.float32)
+    else:
+        q = jax.random.normal(ks[0], (Q, D))
+        g = jax.random.normal(ks[1], (G, D))
     sv, si = ops.reid_topk(q, g, k, block_q=bq, block_g=bg)
     rv, ri = ref.reid_topk_ref(q, g, k)
     np.testing.assert_allclose(sv, rv, rtol=1e-5, atol=1e-5)
+    if ties:
+        np.testing.assert_array_equal(si, ri)
     # indices: permutation-tolerant on ties — compare the score multiset
     np.testing.assert_allclose(np.sort(sv, 1), np.sort(rv, 1), rtol=1e-5)
     # gathered scores must match the claimed scores
@@ -104,20 +119,43 @@ def test_reid_topk_masked_matches_ref():
     np.testing.assert_array_equal(si, ri)
 
 
-def test_reid_topk_segments_matches_ref():
-    """Segment-ID variant == oracle on a mixed (cam, segment) batch."""
+@pytest.mark.parametrize("case", [
+    "mixed",
+    # exact ties across gallery blocks (G > block_g), k in {1, 3, 5}
+    "ties-k1", "ties-k3", "ties-k5",
+    # real hits only in the first gallery block: every later tile is all
+    # NEG_INF and must leave the running top-k (and its sentinels) alone
+    "neg-inf-tail",
+])
+def test_reid_topk_segments_matches_ref(case):
+    """Segment-ID variant == oracle on a mixed (cam, segment) batch, and
+    bit-for-bit on the merge's edge cases."""
     rng = np.random.default_rng(13)
-    Q, G, C, D, k = 11, 83, 6, 32, 4
-    q = jnp.asarray(rng.normal(size=(Q, D)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(G, D)), jnp.float32)
-    q_seg = jnp.asarray(rng.integers(0, 4, Q), jnp.int32)
+    Q, G, C, D, k, bg = 11, 83, 6, 32, 4, 512
+    if case != "mixed":
+        Q, G, D, bg = 21, 600, 8, 128
+        k = int(case[-1]) if case.startswith("ties") else 5
+    draw = (lambda s: rng.integers(0, 2, s)) if case != "mixed" \
+        else (lambda s: rng.normal(size=s))
+    q = jnp.asarray(draw((Q, D)), jnp.float32)
+    g = jnp.asarray(draw((G, D)), jnp.float32)
+    q_seg = rng.integers(0, 4, Q)
     gal_cam = jnp.asarray(rng.integers(0, C, G), jnp.int32)
-    gal_seg = jnp.asarray(rng.integers(0, 4, G), jnp.int32)
+    gal_seg = rng.integers(0, 4, G)
+    if case == "neg-inf-tail":
+        gal_seg[bg:] = 99                   # no query holds segment 99
+        gal_seg[:bg] = np.where(rng.random(bg) < 0.05, gal_seg[:bg], 99)
+    q_seg, gal_seg = jnp.asarray(q_seg, jnp.int32), jnp.asarray(gal_seg,
+                                                                 jnp.int32)
     adm = jnp.asarray(rng.random((Q, C)) < 0.5)
-    sv, si = ops.reid_topk_segments(q, q_seg, adm, g, gal_cam, gal_seg, k)
+    sv, si = ops.reid_topk_segments(q, q_seg, adm, g, gal_cam, gal_seg, k,
+                                    block_q=8, block_g=bg)
     rv, ri = ref.reid_topk_segments_ref(q, q_seg, adm, g, gal_cam, gal_seg, k)
     np.testing.assert_allclose(sv, rv, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(si, ri)
+    if case == "neg-inf-tail":              # rows short of k real hits
+        assert ((np.asarray(si) == -1).any(axis=1)
+                & (np.asarray(si) >= 0).any(axis=1)).any()
 
 
 def test_reid_topk_segments_relabel_bit_identical_to_masked():
@@ -147,23 +185,38 @@ def test_reid_topk_segments_relabel_bit_identical_to_masked():
     np.testing.assert_array_equal(np.asarray(msi), np.asarray(ssi))
 
 
-def test_reid_topk_tiles_matches_ref():
+@pytest.mark.parametrize("case", [
+    "mixed",
+    # camera-sorted gallery and per-q-block camera admission: most
+    # (q-block, g-block) pairs are dead and skip the GEMM + merge entirely
+    "dead-blocks",
+])
+def test_reid_topk_tiles_matches_ref(case):
     """Tile-masked variant == oracle on a mixed (segment, fused-cell) batch
     — including unlabeled gallery rows (``gal_ct == -1``), which must match
     nothing rather than wrap into cell C*T*T - 1."""
     rng = np.random.default_rng(41)
     Q, G, C, T, D, k = 11, 83, 6, 3, 32, 4
+    if case == "dead-blocks":
+        Q, G, D, k = 40, 700, 8, 3
     TT = T * T
     q = jnp.asarray(rng.normal(size=(Q, D)), jnp.float32)
     g = jnp.asarray(rng.normal(size=(G, D)), jnp.float32)
     q_seg = jnp.asarray(rng.integers(0, 4, Q), jnp.int32)
     gal_seg = jnp.asarray(rng.integers(0, 4, G), jnp.int32)
     gal_cam = rng.integers(0, C, G)
+    adm_ct = rng.random((Q, C * TT)) < 0.4
+    if case == "dead-blocks":
+        gal_cam = np.sort(gal_cam)
+        blk_cam = rng.integers(0, C, Q // 8)    # one camera per q-block
+        adm_ct &= np.repeat(np.repeat(blk_cam, 8)[:, None]
+                            == np.arange(C)[None, :], TT, axis=1)
     gal_ct = jnp.asarray(
         np.where(rng.random(G) < 0.15, -1,
                  gal_cam * TT + rng.integers(0, TT, G)), jnp.int32)
-    adm_ct = jnp.asarray(rng.random((Q, C * TT)) < 0.4)
-    sv, si = ops.reid_topk_tiles(q, q_seg, adm_ct, g, gal_ct, gal_seg, k)
+    adm_ct = jnp.asarray(adm_ct)
+    sv, si = ops.reid_topk_tiles(q, q_seg, adm_ct, g, gal_ct, gal_seg, k,
+                                 block_q=8, block_g=128)
     rv, ri = ref.reid_topk_tiles_ref(q, q_seg, adm_ct, g, gal_ct, gal_seg, k)
     np.testing.assert_allclose(sv, rv, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(si, ri)

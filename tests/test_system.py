@@ -180,3 +180,34 @@ def test_every_bench_record_call_site_satisfies_the_schema():
                 f"{fn}:{node.lineno}: bench_record missing explicit " \
                 f"required keys {sorted(required - kw)}"
     assert audited >= 10, f"audit only found {audited} call sites"
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """The launchers' compile cache: JAX_COMPILATION_CACHE_DIR wins and is
+    left to JAX; otherwise a fixed <checkout>/.jax_cache, never a
+    per-process or temporary name.  Either way fast compiles are kept."""
+    import os
+    import jax
+    from repro.launch.serve import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    try:
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert use_compile_cache() == os.path.join(checkout, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == \
+                os.path.join(checkout, ".jax_cache")
+        else:
+            path = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+            assert use_compile_cache() == path
+            assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
