@@ -65,8 +65,8 @@ from repro.core.policy import (PhaseState, SearchPolicy, admit, advance,
 from repro.kernels import ops as kernel_ops
 from repro.kernels.reid_topk import NEG_INF
 from repro.runtime.gallery import (GalleryStore, LocalGalleryStore,
-                                   assemble_round_gallery, l2_normalize,
-                                   pow2)
+                                   RoundStaging, assemble_round_gallery,
+                                   l2_normalize, pow2)
 from repro.runtime.stream_store import FrameStore
 from repro.runtime.transport import PrefetchPipeline
 
@@ -207,6 +207,24 @@ def _rank_outcome(sv, si, gallery, gal_cam, gal_frame, match_thresh,
                               0).astype(jnp.int32)
     idx0 = jnp.maximum(best_idx, 0)
     return matched, match_cam, gallery[idx0], sv, si, topk_cam, topk_frame
+
+
+def match_rows(matched: np.ndarray, match_cam: np.ndarray,
+               topk_idx: np.ndarray, topk_cam: np.ndarray,
+               topk_rerank: bool) -> np.ndarray:
+    """(N,) round-gallery row each matched query matched, -1 elsewhere: the
+    row ``_rank_outcome`` gathers as ``match_emb``.  That is band 0 on the
+    argmax path; under ``topk_rerank`` it is the winning camera's best band,
+    which is its first band, since the bands are score-sorted and the
+    passing ones lead."""
+    rows = np.where(matched, topk_idx[:, 0], -1)
+    if topk_rerank:
+        for j in np.flatnonzero(matched):
+            for b in range(topk_cam.shape[1]):
+                if topk_cam[j, b] == match_cam[j]:
+                    rows[j] = topk_idx[j, b]
+                    break
+    return rows
 
 
 @partial(jax.jit, static_argnames=("match_thresh", "k", "topk_rerank"))
@@ -390,6 +408,7 @@ class RoundPlan:
     ps: PhaseState
     slots: np.ndarray
     mask: np.ndarray                        # (N, C) admission, host copy
+    mask_dev: Any                           # the same mask, on the device
     admitted: int                           # per-(query, camera) steps
     cams_by_q: list
     work: list                              # sorted unique (cam, frame)
@@ -397,16 +416,18 @@ class RoundPlan:
     seg_of_frame: dict                      # content frame -> segment id
     q_seg: np.ndarray                       # (N,) int32, -1 on padding rows
     # tile mode only: the fused (camera, tile) admission (N, C*T*T) the
-    # tile-masked ranking pass consumes; None under camera-granular serving
+    # tile-masked ranking pass consumes (on the device; the host copy feeds
+    # the tile counters); None under camera-granular serving
     mask_ct: np.ndarray | None = None
+    mask_ct_dev: Any = None
 
     def gallery_segments(self, batch_keys: list, key_emb: dict,
-                         rows: int) -> np.ndarray:
-        """Per-row segment tags for the assembled round gallery: each key's
-        embedding block (in ``batch_keys`` order, exactly how
-        ``assemble_round_gallery`` laid the rows out) gets its frame's
-        segment id; padding rows carry -1 like the cam/frame tags."""
-        gal_seg = np.full(rows, -1, np.int32)
+                         gal_seg: np.ndarray) -> np.ndarray:
+        """Write the per-row segment tags of the assembled round gallery
+        into ``gal_seg``: each key's embedding block (in ``batch_keys``
+        order, exactly how ``assemble_round_gallery`` laid the rows out)
+        gets its frame's segment id; the padding rows past them keep the -1
+        the staging holds there."""
         pos = 0
         for key in batch_keys:
             cnt = len(key_emb[key])
@@ -484,6 +505,9 @@ class ServingEngine:
         # time it dips (what RecompileGuard would trip on).
         self._batch_hwm = 1
         self._gal_rows_hwm = 1
+        # the round's host staging (gallery + query features), sized from
+        # those marks and reused across rounds
+        self._staging = RoundStaging()
         self._windows = phase_windows(model, cfg.policy)
         # host copies of the exhaustion windows for the skip fast path
         self._w1 = np.asarray(self._windows.w_end1)
@@ -639,6 +663,14 @@ class ServingEngine:
         same workload without mid-run shape growth."""
         return self._gal_rows_hwm
 
+    @property
+    def staging_allocs(self) -> int:
+        """Allocations or growths of the round's host staging buffers so
+        far.  The buffers follow the primed high-water marks, so after
+        ``prime_batch``/``prime_gallery`` and the first round it holds
+        still through steady serving."""
+        return self._staging.allocs
+
     def _gather(self, qs: list[QueryState]) -> PhaseState:
         """Engine QueryStates -> one batched PhaseState.  The live frontier
         is the engine wall clock: frames through ``self.t`` are ingested.
@@ -667,8 +699,10 @@ class ServingEngine:
 
     def _scatter(self, qs: list[QueryState], ps: PhaseState,
                  matched: np.ndarray, match_cam: np.ndarray,
-                 match_emb: np.ndarray | None):
-        """Write the advanced PhaseState back into the QueryState objects."""
+                 emb_rows: np.ndarray | None, gallery: np.ndarray | None):
+        """Write the advanced PhaseState back into the QueryState objects.
+        A matched query's embedding is row ``emb_rows[j]`` of the host
+        round ``gallery`` the rank step ranked, the row it matched."""
         a = self.policy.feat_alpha
         sl = self._slots
         f_q = np.asarray(ps.f_q)
@@ -679,7 +713,7 @@ class ServingEngine:
         for i, q in enumerate(qs):
             j = sl[i]
             if matched[j]:
-                emb = match_emb[j]
+                emb = gallery[emb_rows[j]]
                 q.feat = l2_normalize((1 - a) * q.feat + a * emb)
                 if q.first_match_t < 0:   # detection delay (Fig. 15 metric)
                     q.first_match_t = self.t
@@ -739,7 +773,7 @@ class ServingEngine:
         ranking pass tags queries and gallery rows with."""
         ps = self._gather(qs)
         sl = self._slots
-        mask_ct = None
+        mask_ct = m_ct = None
         if self.tile_grid > 0:
             # one fused admit pass: the (N, C) camera mask (identical to
             # _dispatch_admit by construction — mask_ct reduces to it over
@@ -749,9 +783,10 @@ class ServingEngine:
             tq = np.full(ps.f_q.shape[0], -1, np.int32)
             tq[sl] = [q.tile_q for q in qs]
             m, m_ct = self._dispatch_admit_tiles(ps, jnp.asarray(tq))
-            mask, mask_ct = np.asarray(m), np.asarray(m_ct)
+            mask_ct = np.asarray(m_ct)
         else:
-            mask = np.asarray(self._dispatch_admit(ps))              # (N, C)
+            m = self._dispatch_admit(ps)
+        mask = np.asarray(m)                                         # (N, C)
         cams_by_q = [np.flatnonzero(mask[sl[i]]) for i in range(len(qs))]
         want_count: dict[tuple[int, int], int] = {}
         for i, q in enumerate(qs):
@@ -763,11 +798,11 @@ class ServingEngine:
         q_seg = np.full(mask.shape[0], -1, np.int32)
         for i, q in enumerate(qs):
             q_seg[sl[i]] = seg_of_frame[q.f_curr]
-        return RoundPlan(qs=qs, ps=ps, slots=sl, mask=mask,
+        return RoundPlan(qs=qs, ps=ps, slots=sl, mask=mask, mask_dev=m,
                          admitted=int(mask[sl].sum()), cams_by_q=cams_by_q,
                          work=sorted(want_count), want_count=want_count,
                          seg_of_frame=seg_of_frame, q_seg=q_seg,
-                         mask_ct=mask_ct)
+                         mask_ct=mask_ct, mask_ct_dev=m_ct)
 
     def _account_round(self, plan: RoundPlan) -> None:
         """Per-round accounting hook over the shared ``RoundPlan`` —
@@ -931,7 +966,9 @@ class ServingEngine:
             # per-key UNION of admitted tiles (the deduplicated sub-frame
             # pixel-load proxy — camera-granular loads T*T per unique key)
             TT = self.tile_grid * self.tile_grid
-            adm_tiles = int(plan.mask_ct[sl].sum())
+            # padding rows are done and admit nothing: the whole mask's
+            # count is the live rows' count
+            adm_tiles = int(plan.mask_ct.sum())
             stats["admitted_tiles"] += adm_tiles
             self.admitted_tiles += adm_tiles
             tiles_by_key: dict[tuple[int, int], np.ndarray] = {}
@@ -1022,16 +1059,18 @@ class ServingEngine:
         topk_idx = np.full((N, K), -1, np.int32)
         topk_cam = np.full((N, K), -1, np.int32)
         topk_frame = np.full((N, K), -1, np.int32)
-        match_emb = None
+        emb_rows = gal = None
         if batch_keys:
             # camera-major key order was fixed above; assembly + pow2 pad
-            # live in the gallery plane so both engines share one rule
+            # live in the gallery plane so both engines share one rule.
+            # The rows land in the engine's own staging, refilled in place
+            # each round (the previous round's results are back on the host)
+            st = self._staging
             gal, gal_cam, gal_frame = assemble_round_gallery(
-                batch_keys, key_emb, min_rows=self._gal_rows_hwm)
+                batch_keys, key_emb, min_rows=self._gal_rows_hwm, out=st)
             self._gal_rows_hwm = max(self._gal_rows_hwm, gal.shape[0])
-            q_feat = np.zeros((N, gal.shape[1]), np.float32)
-            for i, q in enumerate(qs):
-                q_feat[sl[i]] = q.feat
+            Gp = gal.shape[0]
+            q_feat = st.stage_queries(N, sl, [q.feat for q in qs])
             if self.tile_grid > 0:
                 # tile path: ONE tile-masked segment-ID kernel call ranks
                 # the whole round regardless of cfg.consolidate (the
@@ -1040,7 +1079,7 @@ class ServingEngine:
                 # gallery row carries its fused (camera, tile) cell from
                 # the ingest-time labels.
                 TT = self.tile_grid * self.tile_grid
-                gal_ct = np.full(gal.shape[0], -1, np.int32)
+                gal_ct = st.ct[:Gp]
                 pos = 0
                 for key in batch_keys:
                     cnt = len(key_emb[key])
@@ -1056,11 +1095,11 @@ class ServingEngine:
                         np.asarray(tiles_k, np.int32)
                     pos += cnt
                 gal_seg = plan.gallery_segments(batch_keys, key_emb,
-                                                gal.shape[0])
-                (ps_next, m, mc, me, tv, ti, tc,
+                                                st.seg[:Gp])
+                (ps_next, m, mc, _, tv, ti, tc,
                  tf) = self._dispatch_rank_advance_tiles(
                     ps, jnp.asarray(q_feat), jnp.asarray(plan.q_seg),
-                    jnp.asarray(plan.mask_ct), jnp.asarray(gal),
+                    plan.mask_ct_dev, jnp.asarray(gal),
                     jnp.asarray(gal_ct), jnp.asarray(gal_cam),
                     jnp.asarray(gal_frame), jnp.asarray(gal_seg))
             elif self.cfg.consolidate:
@@ -1068,45 +1107,37 @@ class ServingEngine:
                 # whole round — frames relabeled to the plan's compact
                 # segment ids, gal_frame riding along for the trace bands
                 gal_seg = plan.gallery_segments(batch_keys, key_emb,
-                                                gal.shape[0])
-                (ps_next, m, mc, me, tv, ti, tc,
+                                                st.seg[:Gp])
+                (ps_next, m, mc, _, tv, ti, tc,
                  tf) = self._dispatch_rank_advance_seg(
                     ps, jnp.asarray(q_feat), jnp.asarray(plan.q_seg),
-                    jnp.asarray(mask), jnp.asarray(gal),
+                    plan.mask_dev, jnp.asarray(gal),
                     jnp.asarray(gal_cam), jnp.asarray(gal_frame),
                     jnp.asarray(gal_seg))
             else:
-                (ps_next, m, mc, me, tv, ti, tc,
+                (ps_next, m, mc, _, tv, ti, tc,
                  tf) = self._dispatch_rank_advance(
-                    ps, jnp.asarray(q_feat), jnp.asarray(mask),
+                    ps, jnp.asarray(q_feat), plan.mask_dev,
                     jnp.asarray(gal), jnp.asarray(gal_cam),
                     jnp.asarray(gal_frame))
+            # the step's match_emb stays on the device: each matched row
+            # is already in the host gallery, at the index the step chose
             matched = np.asarray(m)
             match_cam = np.asarray(mc)
-            match_emb = np.asarray(me)
             topk_val = np.asarray(tv)
             topk_idx = np.asarray(ti)
             topk_cam = np.asarray(tc)
             topk_frame = np.asarray(tf)
+            emb_rows = match_rows(matched, match_cam, topk_idx, topk_cam,
+                                  self.cfg.topk_rerank)
             stats["matches"] += int(matched[sl].sum())
             if self.tile_grid > 0:
                 # follow-window state: a confirmed match pins the query to
                 # the matched gallery row's tile (gal_ct carries the fused
                 # cell; % T*T recovers the tile) — the next round's learned
                 # self-camera admission narrows around it
-                TT = self.tile_grid * self.tile_grid
                 for i, q in enumerate(qs):
-                    j = sl[i]
-                    if not matched[j]:
-                        continue
-                    mi = int(topk_idx[j, 0])
-                    if self.cfg.topk_rerank:
-                        # re-ranked matches re-anchor to the winning
-                        # camera's best band, not band 0
-                        for b in range(K):
-                            if topk_cam[j, b] == match_cam[j]:
-                                mi = int(topk_idx[j, b])
-                                break
+                    mi = emb_rows[sl[i]]
                     if mi >= 0 and gal_ct[mi] >= 0:
                         q.tile_q = int(gal_ct[mi]) % TT
         else:
@@ -1126,7 +1157,7 @@ class ServingEngine:
                                 int(topk_frame[j, b])) for b in range(K)))
             trace.extend(records[q.qid] for q in all_qs)
 
-        self._scatter(qs, ps_next, matched, match_cam, match_emb)
+        self._scatter(qs, ps_next, matched, match_cam, emb_rows, gal)
 
         # double-buffer: with the round's outcomes scattered, the cohort's
         # NEXT cursors are known — speculate round N+1's admission and start

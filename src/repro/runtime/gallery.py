@@ -389,9 +389,63 @@ class ShardedGalleryStore(GalleryStore):
         return rep
 
 
+class RoundStaging:
+    """Host buffers one engine stages every round's inputs in, reused from
+    round to round: the padded round gallery ``gal`` (rows, D) float32 with
+    its per-row tags ``cam``, ``frame``, ``seg`` (compact segment id) and
+    ``ct`` (fused camera-tile cell), and the (N, D) float32 query features
+    ``q_feat``.  Buffers grow to a new high-water mark and never shrink, so
+    a primed engine allocates them once; ``allocs`` counts every allocation
+    or growth.  Rows past the last fill are zero with tags -1 (the padding
+    contract), kept so by resetting only the rows the previous round wrote
+    and the current one does not.
+
+    A buffer is refilled only once the round that read it has its results
+    back on the host (that read blocks on the step that consumed the
+    upload), so no array built from a buffer may outlive its round."""
+
+    def __init__(self):
+        self.gal = np.zeros((0, 0), np.float32)
+        self.cam = self.frame = self.seg = self.ct = np.zeros(0, np.int32)
+        self.rows = 0             # real gallery rows of the last fill
+        self.q_feat = np.zeros((0, 0), np.float32)
+        self._q_used = np.zeros(0, bool)   # q_feat rows the last fill wrote
+        self.allocs = 0
+
+    def reserve_gallery(self, rows: int, dim: int) -> None:
+        """Hold at least ``rows`` gallery rows of width ``dim``."""
+        if rows <= self.gal.shape[0] and dim == self.gal.shape[1]:
+            return
+        rows = max(rows, self.gal.shape[0])
+        self.gal = np.zeros((rows, dim), np.float32)
+        self.cam, self.frame, self.seg, self.ct = (
+            np.full(rows, -1, np.int32) for _ in range(4))
+        self.rows = 0
+        self.allocs += 1
+
+    def stage_queries(self, n: int, slots: np.ndarray, feats: list):
+        """Write ``feats[i]`` into row ``slots[i]`` of the (n, D) query
+        block, zero the rows the previous fill wrote and this one does not,
+        and return the block."""
+        dim = len(feats[0])
+        if n > self.q_feat.shape[0] or dim != self.q_feat.shape[1]:
+            self.q_feat = np.zeros((max(n, self.q_feat.shape[0]), dim),
+                                   np.float32)
+            self._q_used = np.zeros(self.q_feat.shape[0], bool)
+            self.allocs += 1
+        used = np.zeros_like(self._q_used)
+        used[slots] = True
+        self.q_feat[self._q_used & ~used] = 0.0
+        self._q_used = used
+        for j, f in zip(slots, feats):
+            self.q_feat[j] = f
+        return self.q_feat[:n]
+
+
 def assemble_round_gallery(batch_keys: list[tuple[int, int]],
                            key_emb: dict[tuple[int, int], np.ndarray],
-                           min_rows: int = 1):
+                           min_rows: int = 1,
+                           out: RoundStaging | None = None):
     """One round's deduplicated gallery, engine-ready: concatenate the
     per-key embedding blocks IN ``batch_keys`` ORDER (the engines pass
     camera-major sorted keys, which is what keeps the kernel's flat-argmin
@@ -402,17 +456,22 @@ def assemble_round_gallery(batch_keys: list[tuple[int, int]],
     its high-water mark (growth-only padding, so the jitted rank signature
     stays frozen when a round's gallery shrinks — padded rows can never win
     a tie, the kernel's flat argmin always resolves equal scores to the
-    lowest real column).  Returns (gallery (Gp, D), gal_cam (Gp,),
-    gal_frame (Gp,))."""
+    lowest real column).  The rows are written in place into ``out`` (a
+    fresh ``RoundStaging`` when None), whose padding rows past the real
+    ones come back zero with every tag -1.  Returns views of ``out``:
+    (gallery (Gp, D), gal_cam (Gp,), gal_frame (Gp,))."""
+    out = RoundStaging() if out is None else out
     counts = [len(key_emb[k]) for k in batch_keys]
-    gal = np.concatenate([key_emb[k] for k in batch_keys]).astype(np.float32)
-    gal_cam = np.repeat([k[0] for k in batch_keys], counts).astype(np.int32)
-    gal_frame = np.repeat([k[1] for k in batch_keys], counts).astype(np.int32)
-    G = gal.shape[0]
+    G = sum(counts)
     Gp = max(pow2(G), pow2(min_rows))
-    if Gp > G:
-        gal = np.concatenate(
-            [gal, np.zeros((Gp - G, gal.shape[1]), np.float32)])
-        gal_cam = np.concatenate([gal_cam, np.full(Gp - G, -1, np.int32)])
-        gal_frame = np.concatenate([gal_frame, np.full(Gp - G, -1, np.int32)])
-    return gal, gal_cam, gal_frame
+    out.reserve_gallery(Gp, key_emb[batch_keys[0]].shape[1])
+    np.concatenate([key_emb[k] for k in batch_keys], out=out.gal[:G])
+    out.cam[:G] = np.repeat([k[0] for k in batch_keys], counts)
+    out.frame[:G] = np.repeat([k[1] for k in batch_keys], counts)
+    if out.rows > G:
+        stale = slice(G, out.rows)
+        out.gal[stale] = 0.0
+        for tag in (out.cam, out.frame, out.seg, out.ct):
+            tag[stale] = -1
+    out.rows = G
+    return out.gal[:Gp], out.cam[:Gp], out.frame[:Gp]

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from repro.runtime import FrameStore
-from repro.runtime.gallery import (LocalGalleryStore, ShardedGalleryStore,
+from repro.runtime.gallery import (LocalGalleryStore, RoundStaging,
+                                   ShardedGalleryStore,
                                    assemble_round_gallery, pow2)
 
 
@@ -112,6 +113,23 @@ def test_assemble_round_gallery_camera_major_and_pow2():
     np.testing.assert_array_equal(gal_frame[:5], [5, 5, 5, 4, 4])
     assert (gal_cam[5:] == -1).all() and (gal_frame[5:] == -1).all()
     assert (gal[5:] == 0).all()
+    # the in-place form: two fills of one staging, the second shorter,
+    # leave zero rows and -1 tags (segment and cell tags too) past its end
+    st = RoundStaging()
+    assemble_round_gallery(keys, key_emb, min_rows=8, out=st)
+    st.seg[:5] = 7
+    st.ct[:5] = 9
+    gal2, cam2, frame2 = assemble_round_gallery([(2, 4)], key_emb,
+                                                min_rows=8, out=st)
+    assert st.allocs == 1 and gal2.shape == (8, 4)
+    assert np.shares_memory(gal2, st.gal)
+    np.testing.assert_array_equal(gal2[:2], key_emb[(2, 4)])
+    np.testing.assert_array_equal(cam2[:2], [2, 2])
+    np.testing.assert_array_equal(frame2[:2], [4, 4])
+    assert (gal2[2:] == 0).all()
+    for tag in (cam2, frame2, st.seg[:8], st.ct[:8]):
+        assert (tag[2:] == -1).all()
+    np.testing.assert_array_equal(st.seg[:2], [7, 7])   # the engine's rows
 
 
 # -- FrameStore delegation ---------------------------------------------------
