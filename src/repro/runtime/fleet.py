@@ -34,6 +34,26 @@ gallery shards onto the survivors — an elastic scale-down, not a restart.
 An optional ``HeartbeatMonitor`` drives the same path from
 liveness/straggler signals via ``poll_health``.
 
+What crosses the host link, per round (counted by the fleet, see
+``shard_report``; the profiler sees ``rex.fleet.layout`` around the
+placement grouping and ``rex.fleet.dispatch`` around each step call):
+
+  * every rank dispatch sends the round gallery and its tags REPLICATED:
+    each live worker receives the whole padded (Gp, D) float32 gallery and
+    its int32 tag vectors (``replicated_upload_bytes``, counted once per
+    receiving worker: 256 rows at D=2,048 is 2 MiB a worker, 8 MiB over
+    four).  The geo adjacency is a (C, C) device array on one chip that the
+    admit dispatch re-broadcasts, uncounted;
+  * the state columns, the query features and segment ids go SHARDED: each
+    worker receives its block of rows;
+  * the sharded outputs the host reads back (admission masks, match and
+    top-k columns, the advanced state but its ``live_f``;
+    ``sharded_readback_bytes``) come one block from each worker; the
+    matched embeddings stay on the devices;
+  * the embedding plane's own transfers are the gallery store's
+    (``ShardedGalleryStore``: a block put on its owner's chip for every
+    fresh embedding, a blocking read back for every cache hit).
+
 Because admission, ranking and the phase machine are pure per-query maps
 (the gallery is shared, not recomputed), placement never changes results —
 worker loss mid-run keeps the trace bit-identical.  What sharding buys is
@@ -55,8 +75,10 @@ from repro.runtime.engine import (EngineConfig, QueryState, RoundPlan,
                                   ServingEngine, _pow2, advance_round,
                                   rank_advance_round, rank_advance_round_seg,
                                   rank_advance_round_tiles)
-from repro.runtime.gallery import (GalleryStore, LocalGalleryStore,
-                                   ShardedGalleryStore)
+from repro.runtime.gallery import (OWNER_COUNTERS, GalleryStore,
+                                   LocalGalleryStore, ShardedGalleryStore)
+
+_span = jax.profiler.TraceAnnotation
 
 
 def make_sharded_step_fns(mesh, policy, topk: int, topk_rerank: bool = False,
@@ -170,9 +192,15 @@ class ShardedServingEngine(ServingEngine):
         # owned_frames is its slice of the fleet-GLOBAL dedup set (which
         # camera-owner would serve each deduplicated frame) — the two cost
         # views the gallery plane distinguishes.
+        # replicated_upload_bytes / sharded_readback_bytes: the host-link
+        # bytes each worker received replicated / sent back (module doc)
         self._shard_stats = {w: dict(admitted_steps=0, unique_frames=0,
-                                     owned_frames=0, query_rounds=0)
+                                     owned_frames=0, query_rounds=0,
+                                     replicated_upload_bytes=0,
+                                     sharded_readback_bytes=0)
                              for w in self._all_workers}
+        self.replicated_upload_bytes = 0
+        self.sharded_readback_bytes = 0
         self.rebalances = 0
         self._block_hwm = 1          # per-shard batch rows high-water mark
         # transport dead-peer signal: a fetch whose retry budget exhausts
@@ -371,19 +399,21 @@ class ShardedServingEngine(ServingEngine):
         ``shard_map`` splits the padded batch into exactly the host-side
         placement.  Padding rows are ``done`` (admit nothing, rank to
         (NEG_INF, -1)) just like the single engine's."""
-        groups: list[list[int]] = [[] for _ in self._workers]
-        for i, q in enumerate(qs):
-            groups[self._shard_of[self._placement[q.qid]]].append(i)
-        block = _pow2(max(max((len(g) for g in groups), default=0), 1))
-        # shard-block high-water mark: a shrinking cohort keeps the compiled
-        # per-shard block (padding rows are done), so steady state never
-        # mints a smaller shard_map signature (RecompileGuard's contract)
-        self._block_hwm = max(self._block_hwm, block)
-        block = self._block_hwm
-        slots = np.zeros(len(qs), np.int64)
-        for s, g in enumerate(groups):
-            slots[g] = s * block + np.arange(len(g))
-        return len(self._workers) * block, slots
+        with _span("rex.fleet.layout"):
+            groups: list[list[int]] = [[] for _ in self._workers]
+            for i, q in enumerate(qs):
+                groups[self._shard_of[self._placement[q.qid]]].append(i)
+            block = _pow2(max(max((len(g) for g in groups), default=0), 1))
+            # shard-block high-water mark: a shrinking cohort keeps the
+            # compiled per-shard block (padding rows are done), so steady
+            # state never mints a smaller shard_map signature
+            # (RecompileGuard's contract)
+            self._block_hwm = max(self._block_hwm, block)
+            block = self._block_hwm
+            slots = np.zeros(len(qs), np.int64)
+            for s, g in enumerate(groups):
+                slots[g] = s * block + np.arange(len(g))
+            return len(self._workers) * block, slots
 
     def prime_batch(self, n_queries: int) -> None:
         """Fleet variant of the single engine's ``prime_batch``: pre-size
@@ -403,30 +433,79 @@ class ShardedServingEngine(ServingEngine):
                 topk_rerank=self.cfg.topk_rerank, n_cams=self.C)
         return self._sharded_fns
 
+    def _uploaded(self, *arrays) -> None:
+        """Charge arrays one dispatch sends replicated: every live worker
+        receives each one whole."""
+        nbytes = sum(a.nbytes for a in arrays)
+        for w in self._workers:
+            self._shard_stats[w]["replicated_upload_bytes"] += nbytes
+        self.replicated_upload_bytes += nbytes * len(self._workers)
+
+    def _read_back(self, *arrays) -> None:
+        """Charge sharded outputs the round copies to the host: each live
+        worker sends its equal block of rows."""
+        nbytes = sum(a.nbytes for a in arrays)
+        for w in self._workers:
+            self._shard_stats[w]["sharded_readback_bytes"] += \
+                nbytes // len(self._workers)
+        self.sharded_readback_bytes += nbytes
+
+    @staticmethod
+    def _state_read(ps):
+        """The columns of an advanced state the round scatters back to its
+        queries (all but ``live_f``)."""
+        return ps.f_q, ps.c_q, ps.f_curr, ps.phase, ps.done
+
+    def _rank_read(self, out):
+        """Charge a rank step's read-back outputs; the matched embeddings
+        (index 3) stay on the devices."""
+        nxt, m, mc, _, tv, ti, tc, tf = out
+        self._read_back(*self._state_read(nxt), m, mc, tv, ti, tc, tf)
+        return out
+
     def _dispatch_admit(self, ps):
-        return self._fns()[0](self.model, ps, self._geo_adj)
+        with _span("rex.fleet.dispatch"):
+            m = self._fns()[0](self.model, ps, self._geo_adj)
+        self._read_back(m)
+        return m
 
     def _dispatch_admit_tiles(self, ps, tile_q):
-        return self._fns()[4](self.model, ps, self._geo_adj, tile_q)
+        with _span("rex.fleet.dispatch"):
+            m, m_ct = self._fns()[4](self.model, ps, self._geo_adj, tile_q)
+        self._read_back(m, m_ct)
+        return m, m_ct
 
     def _dispatch_rank_advance(self, ps, q_feat, mask, gallery, gal_cam,
                                gal_frame):
-        return self._fns()[1](self._windows, ps, q_feat, mask, gallery,
-                              gal_cam, gal_frame)
+        self._uploaded(gallery, gal_cam, gal_frame)
+        with _span("rex.fleet.dispatch"):
+            out = self._fns()[1](self._windows, ps, q_feat, mask, gallery,
+                                 gal_cam, gal_frame)
+        return self._rank_read(out)
 
     def _dispatch_rank_advance_seg(self, ps, q_feat, q_seg, mask, gallery,
                                    gal_cam, gal_frame, gal_seg):
-        return self._fns()[2](self._windows, ps, q_feat, q_seg, mask,
-                              gallery, gal_cam, gal_frame, gal_seg)
+        self._uploaded(gallery, gal_cam, gal_frame, gal_seg)
+        with _span("rex.fleet.dispatch"):
+            out = self._fns()[2](self._windows, ps, q_feat, q_seg, mask,
+                                 gallery, gal_cam, gal_frame, gal_seg)
+        return self._rank_read(out)
 
     def _dispatch_rank_advance_tiles(self, ps, q_feat, q_seg, mask_ct,
                                      gallery, gal_ct, gal_cam, gal_frame,
                                      gal_seg):
-        return self._fns()[5](self._windows, ps, q_feat, q_seg, mask_ct,
-                              gallery, gal_ct, gal_cam, gal_frame, gal_seg)
+        self._uploaded(gallery, gal_ct, gal_cam, gal_frame, gal_seg)
+        with _span("rex.fleet.dispatch"):
+            out = self._fns()[5](self._windows, ps, q_feat, q_seg, mask_ct,
+                                 gallery, gal_ct, gal_cam, gal_frame,
+                                 gal_seg)
+        return self._rank_read(out)
 
     def _dispatch_advance(self, ps):
-        return self._fns()[3](self._windows, ps)
+        with _span("rex.fleet.dispatch"):
+            nxt = self._fns()[3](self._windows, ps)
+        self._read_back(*self._state_read(nxt))
+        return nxt
 
     # -- per-shard cost accounting ----------------------------------------
     def _account_round(self, plan: RoundPlan) -> None:
@@ -461,20 +540,23 @@ class ShardedServingEngine(ServingEngine):
         total), ``unique_frames`` (shard-local demand: what a replicated
         per-worker cache would embed) and ``owned_frames`` (the worker's
         slice of the fleet-global dedup set; sums to the engine's
-        ``unique_frames`` when the gallery is sharded)."""
+        ``unique_frames`` when the gallery is sharded) — and its host-link
+        bytes: ``replicated_upload_bytes``, ``sharded_readback_bytes`` and,
+        with the sharded gallery, its owner transfers (``OWNER_COUNTERS``).
+        Each byte column sums to the fleet's counter."""
         live = set(self._workers)
         rows = [dict(worker=w, device=self._device_of[w].id,
                      alive=w in live,
                      queries=self._load(w) if w in live else 0,
                      **self._shard_stats[w])
                 for w in self._all_workers]
-        if getattr(self.gallery, "transport", None) is not None:
-            # fetch-plane traffic per owner peer: prefetch efficiency and
-            # fault pressure are observable per worker
+        if isinstance(self.gallery, ShardedGalleryStore):
             per_w = self.gallery.per_worker_report()
+            keys = OWNER_COUNTERS
+            if self.gallery.transport is not None:
+                # fetch-plane traffic per owner peer: prefetch efficiency
+                # and fault pressure are observable per worker
+                keys += ("remote_fetches", "retries", "timeouts")
             for row in rows:
-                st = per_w[row["worker"]]
-                row["remote_fetches"] = st["remote_fetches"]
-                row["retries"] = st["retries"]
-                row["timeouts"] = st["timeouts"]
+                row.update((k, per_w[row["worker"]][k]) for k in keys)
         return rows

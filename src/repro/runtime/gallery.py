@@ -56,6 +56,11 @@ def l2_normalize(a: np.ndarray, eps: float = 1e-9) -> np.ndarray:
     return a / np.maximum(np.linalg.norm(a, axis=-1, keepdims=True), eps)
 
 
+# ShardedGalleryStore's transfer counters, per owner worker
+OWNER_COUNTERS = ("owner_puts", "owner_put_bytes", "owner_reads",
+                  "owner_read_bytes")
+
+
 def _cam_hash(cam: int) -> int:
     """Stable camera hash (Knuth multiplicative) for owner-shard choice —
     spreads consecutive camera ids instead of striping them."""
@@ -247,6 +252,27 @@ class ShardedGalleryStore(GalleryStore):
     batches); values round-trip the device bit-exactly, which is what keeps
     the sharded-gallery fleet trace-identical to the single engine.
 
+    What each transfer costs, and when it happens:
+
+    * ``put`` — every freshly embedded (camera, frame) block: one
+      host-to-device copy of the padded block, pow2(n) * D * 4 bytes, onto
+      the owner's chip (8 KiB a row at D=2,048).  The round that embeds a
+      frame ranks it from the engine's own host copy, so the owner copy is
+      written for later rounds only.
+    * ``get`` hit — any later round asking for the frame again: a replay
+      re-read, a rewound cursor, or, on live traffic, a query a step behind
+      the live edge ranking a frame others embedded the tick before.  One
+      blocking device-to-host copy of the whole padded block
+      (``np.asarray``), sliced to its n rows.
+    * ``rehome`` — a device-to-host-to-device round trip per migrated block,
+      once per worker loss; not counted below.
+
+    ``counters()`` and ``per_worker_report()`` count the first two per
+    owner: ``owner_puts`` / ``owner_put_bytes`` and ``owner_reads`` /
+    ``owner_read_bytes``, bytes at the padded device shape.  The profiler
+    sees them as the ``rex.fleet.owner_put`` and ``rex.fleet.owner_read``
+    spans.
+
     With a ``transport`` (``runtime.transport``), every fetch of an
     owner-resident block goes through the fetch plane addressed to the
     block's owner peer — in-proc that is a zero-copy read, fake-RPC it
@@ -273,6 +299,9 @@ class ShardedGalleryStore(GalleryStore):
         self._blocks: dict[tuple[int, int], tuple[Any, int]] = {}
         self.rehomed_blocks = 0
         self.transport = transport
+        # per owner: blocks put / read back and their padded device bytes
+        self._owner_io = {w: dict.fromkeys(OWNER_COUNTERS, 0)
+                          for w in self._device_of}
 
     def owner_of(self, cam: int) -> str:
         return self._owner[cam]
@@ -286,25 +315,39 @@ class ShardedGalleryStore(GalleryStore):
         if rows > n:
             emb = np.concatenate(
                 [emb, np.zeros((rows - n,) + emb.shape[1:], emb.dtype)])
-        self._blocks[(cam, t)] = (
-            jax.device_put(emb, self._device_of[self._owner[cam]]), n)
+        owner = self._owner[cam]
+        with jax.profiler.TraceAnnotation("rex.fleet.owner_put"):
+            arr = jax.device_put(emb, self._device_of[owner])
+        self._blocks[(cam, t)] = (arr, n)
+        io = self._owner_io[owner]
+        io["owner_puts"] += 1
+        io["owner_put_bytes"] += emb.nbytes
 
-    @staticmethod
-    def _read_block(blk):
+    def _read_block(self, owner, blk):
+        """Copy one resident block back to the host (blocking) and count it
+        against ``owner``."""
+        import jax
+
         arr, n = blk
-        return np.asarray(arr)[:n]
+        with jax.profiler.TraceAnnotation("rex.fleet.owner_read"):
+            host = np.asarray(arr)
+        io = self._owner_io[owner]
+        io["owner_reads"] += 1
+        io["owner_read_bytes"] += host.nbytes
+        return host[:n]
 
     def _fetch(self, cam, t):
         while True:
             blk = self._blocks.get((cam, t))
             if blk is None:
                 return None
-            if self.transport is None:
-                return self._read_block(blk)
             owner = self._owner[cam]
+            if self.transport is None:
+                return self._read_block(owner, blk)
             try:
-                return self.transport.fetch(owner, (cam, t),
-                                            lambda b=blk: self._read_block(b))
+                return self.transport.fetch(
+                    owner, (cam, t),
+                    lambda o=owner, b=blk: self._read_block(o, b))
             except PeerDeadError:
                 if self._owner[cam] == owner:
                     raise          # nobody re-homed the camera: surface it
@@ -315,8 +358,9 @@ class ShardedGalleryStore(GalleryStore):
         if self.transport is None:
             return super()._fetch_async(cam, t)
         blk = self._blocks[(cam, t)]
-        return self.transport.fetch_async(self._owner[cam], (cam, t),
-                                          lambda: self._read_block(blk))
+        owner = self._owner[cam]
+        return self.transport.fetch_async(
+            owner, (cam, t), lambda: self._read_block(owner, blk))
 
     def wait_fetch(self, handle):
         if isinstance(handle, LocalFetchHandle):
@@ -358,6 +402,8 @@ class ShardedGalleryStore(GalleryStore):
 
     def counters(self):
         c = dict(super().counters(), rehomed_blocks=self.rehomed_blocks)
+        for k in OWNER_COUNTERS:
+            c[k] = sum(io[k] for io in self._owner_io.values())
         if self.transport is not None:
             c.update(self.transport.counters())
         return c
@@ -365,11 +411,13 @@ class ShardedGalleryStore(GalleryStore):
     def per_worker_report(self) -> dict[str, dict]:
         """Owner-resident cache memory, per worker: cameras owned, resident
         blocks/rows/bytes, blocks ``misplaced`` off the owner's device
-        (always 0 unless placement is broken), plus the fetch plane's
+        (always 0 unless placement is broken), the owner transfers so far
+        (``OWNER_COUNTERS``, kept by a lost worker), plus the fetch plane's
         per-peer traffic when a transport is attached.  Lost workers report
-        zeros after ``rehome``."""
+        zero residency after ``rehome``."""
         rep = {w: dict(cameras=0, blocks=0, rows=0, bytes=0, misplaced=0,
-                       remote_fetches=0, retries=0, timeouts=0)
+                       remote_fetches=0, retries=0, timeouts=0,
+                       **self._owner_io[w])
                for w in self._device_of}
         for w in self._owner.values():
             rep[w]["cameras"] += 1
