@@ -161,7 +161,7 @@ def entries(include_fleet: bool = True) -> list[JitEntry]:
                           dict(interpret=True))),
         JitEntry("policy.admit_tiles", fns["policy.admit_tiles"],
                  lambda: ((w["model_tiles"], w["policy"], w["state"], None,
-                           w["tile_q"]), {})),
+                           w["tile_q"], w["q_seg"]), {})),
         JitEntry("rank_round_tiles", fns["rank_round_tiles"],
                  lambda: ((w["q_feat"], w["q_seg"], w["mask_ct"], w["gal"],
                            w["gal_ct"], w["gal_cam"], w["gal_frame"],
@@ -200,7 +200,7 @@ def entries(include_fleet: bool = True) -> list[JitEntry]:
                      lambda: ((w["windows"], w["state"]), {})),
             JitEntry("fleet.admit_tiles@shard_map", f_admit_tiles,
                      lambda: ((w["model_tiles"], w["state"], None,
-                               w["tile_q"]), {})),
+                               w["tile_q"], w["q_seg"]), {})),
             JitEntry("fleet.rank_advance_tiles@shard_map", f_rank_tiles,
                      lambda: ((w["windows"], w["state"], w["q_feat"],
                                w["q_seg"], w["mask_ct"], w["gal"],

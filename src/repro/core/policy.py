@@ -336,6 +336,26 @@ def admit_tiles(model: "SpatioTemporalModel", policy: SearchPolicy,
     return mask, mask_ct
 
 
+def tile_counts(mask_ct: jnp.ndarray, q_seg: jnp.ndarray):
+    """The round's tile cost in both conventions, as int32 scalars:
+    ``admitted`` counts the (query, camera, tile) steps of ``mask_ct``
+    (Q, C*T*T); ``unique`` counts, per content-frame segment ``q_seg`` (Q,),
+    the union of its queries' admitted cells — the deduplicated sub-frame
+    pixel load, one (camera, frame) key's tiles at a time.  Padding rows
+    (``q_seg < 0``) are done and admit nothing, and belong to no segment.
+
+    The union is a segment one-hot (Q, Q) times the mask: every cell of the
+    product counts its segment's admitting rows, exactly — 0/1 inputs are
+    exact in bfloat16 and Q <= 2**24 rows sum exactly in float32."""
+    Q = mask_ct.shape[0]
+    onehot = q_seg[None, :] == jnp.arange(Q, dtype=q_seg.dtype)[:, None]
+    per_seg = jnp.dot(onehot.astype(jnp.bfloat16),
+                      mask_ct.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return (jnp.sum(mask_ct, dtype=jnp.int32),
+            jnp.sum(per_seg > 0, dtype=jnp.int32))
+
+
 # ---------------------------------------------------------------------------
 # advance — the one phase-machine transition.
 # ---------------------------------------------------------------------------

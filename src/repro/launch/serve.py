@@ -257,6 +257,8 @@ def main():
               f"{c['prefetch_wasted']} wasted speculations), "
               f"{c['retries']} retries, {c['timeouts']} timeouts, "
               f"{c.get('dead_peers', 0)} dead peers")
+    print(f"host reads: {eng.readback_bytes} bytes copied back from the "
+          f"device over {eng.ticks} ticks")
     print(f"wall: {wall:.2f}s ({args.steps/max(wall,1e-9):.0f} steps/s)")
     if args.recalibrate:
         ev = eng.recal.events

@@ -60,8 +60,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.correlation import SpatioTemporalModel
-from repro.core.policy import (PhaseState, SearchPolicy, admit, advance,
-                               phase_windows, replay_sampled_out)
+from repro.core.policy import (PhaseState, SearchPolicy, admit, admit_tiles,
+                               advance, phase_windows, replay_sampled_out,
+                               tile_counts)
 from repro.kernels import ops as kernel_ops
 from repro.kernels.reid_topk import NEG_INF
 from repro.runtime.gallery import (GalleryStore, LocalGalleryStore,
@@ -152,9 +153,12 @@ def _admit_jit(model, policy: SearchPolicy, state: PhaseState, geo_adj=None):
 
 @partial(jax.jit, static_argnames=("policy",))
 def _admit_tiles_jit(model, policy: SearchPolicy, state: PhaseState,
-                     geo_adj=None, tile_q=None):
-    from repro.core.policy import admit_tiles
-    return admit_tiles(model, policy, state, geo_adj, tile_q)
+                     geo_adj, tile_q, q_seg):
+    """The tile round's admit program: both masks, plus the round's
+    (admitted, unique) tile counts as one (2,) int32 array, so the host
+    reads two ints instead of the (N, C*T*T) mask."""
+    mask, mask_ct = admit_tiles(model, policy, state, geo_adj, tile_q)
+    return mask, mask_ct, jnp.stack(tile_counts(mask_ct, q_seg))
 
 
 def _rank_outcome(sv, si, gallery, gal_cam, gal_frame, match_thresh,
@@ -415,11 +419,13 @@ class RoundPlan:
     want_count: dict                        # key -> wanting (q, cam) pairs
     seg_of_frame: dict                      # content frame -> segment id
     q_seg: np.ndarray                       # (N,) int32, -1 on padding rows
-    # tile mode only: the fused (camera, tile) admission (N, C*T*T) the
-    # tile-masked ranking pass consumes (on the device; the host copy feeds
-    # the tile counters); None under camera-granular serving
-    mask_ct: np.ndarray | None = None
+    # tile mode only: the fused (camera, tile) admission (N, C*T*T) and
+    # ``q_seg`` on the device, for the tile-masked ranking pass, and the
+    # round's tile counts the admit program computed from them
     mask_ct_dev: Any = None
+    q_seg_dev: Any = None
+    admitted_tiles: int = 0
+    unique_tiles: int = 0
 
     def gallery_segments(self, batch_keys: list, key_emb: dict,
                          gal_seg: np.ndarray) -> np.ndarray:
@@ -474,6 +480,8 @@ class ServingEngine:
         # camera-granular serving loads T*T tiles per admitted step / key)
         self.admitted_tiles = 0
         self.unique_tiles = 0
+        # bytes the rounds' blocking device reads copied to the host
+        self.readback_bytes = 0
         self.content_steps = 0       # per-query content rounds charged
         self.replay_steps = 0        # content rounds behind the frontier
         self.skipped_steps = 0       # short-circuited sampled-out rounds
@@ -671,6 +679,18 @@ class ServingEngine:
         still through steady serving."""
         return self._staging.allocs
 
+    def _read(self, *arrays):
+        """Copy device arrays to the host, counting the bytes in
+        ``readback_bytes``.  Several arrays go in one ``jax.device_get``:
+        their copies overlap and the host waits once."""
+        if len(arrays) == 1:
+            out = np.asarray(arrays[0])
+            self.readback_bytes += out.nbytes
+            return out
+        out = jax.device_get(arrays)
+        self.readback_bytes += sum(a.nbytes for a in out)
+        return out
+
     def _gather(self, qs: list[QueryState]) -> PhaseState:
         """Engine QueryStates -> one batched PhaseState.  The live frontier
         is the engine wall clock: frames through ``self.t`` are ingested.
@@ -705,11 +725,11 @@ class ServingEngine:
         round ``gallery`` the rank step ranked, the row it matched."""
         a = self.policy.feat_alpha
         sl = self._slots
-        f_q = np.asarray(ps.f_q)
-        c_q = np.asarray(ps.c_q)
-        f_curr = np.asarray(ps.f_curr)
-        phase = np.asarray(ps.phase)
-        done = np.asarray(ps.done)
+        f_q = self._read(ps.f_q)
+        c_q = self._read(ps.c_q)
+        f_curr = self._read(ps.f_curr)
+        phase = self._read(ps.phase)
+        done = self._read(ps.done)
         for i, q in enumerate(qs):
             j = sl[i]
             if matched[j]:
@@ -735,9 +755,9 @@ class ServingEngine:
     def _dispatch_admit(self, ps: PhaseState):
         return _admit_jit(self.model, self.policy, ps, self._geo_adj)
 
-    def _dispatch_admit_tiles(self, ps: PhaseState, tile_q):
+    def _dispatch_admit_tiles(self, ps: PhaseState, tile_q, q_seg):
         return _admit_tiles_jit(self.model, self.policy, ps, self._geo_adj,
-                                tile_q)
+                                tile_q, q_seg)
 
     def _dispatch_rank_advance(self, ps: PhaseState, q_feat, mask, gallery,
                                gal_cam, gal_frame):
@@ -773,36 +793,44 @@ class ServingEngine:
         ranking pass tags queries and gallery rows with."""
         ps = self._gather(qs)
         sl = self._slots
-        mask_ct = m_ct = None
+        N = ps.f_q.shape[0]
+        seg_of_frame = {f: s for s, f in
+                        enumerate(sorted({q.f_curr for q in qs}))}
+        q_seg = np.full(N, -1, np.int32)
+        for i, q in enumerate(qs):
+            q_seg[sl[i]] = seg_of_frame[q.f_curr]
+        m_ct = q_seg_dev = None
+        counts = (0, 0)
         if self.tile_grid > 0:
             # one fused admit pass: the (N, C) camera mask (identical to
             # _dispatch_admit by construction — mask_ct reduces to it over
-            # the tile axis) plus the (N, C*T*T) tile-refined admission.
-            # tile_q rides along padded like every batch column (-1 =
-            # unknown, which admits every self tile)
-            tq = np.full(ps.f_q.shape[0], -1, np.int32)
+            # the tile axis), the (N, C*T*T) tile-refined admission, which
+            # stays on the device for the rank step, and the round's tile
+            # counts, read back with the camera mask.  tile_q rides along
+            # padded like every batch column (-1 = unknown, which admits
+            # every self tile)
+            tq = np.full(N, -1, np.int32)
             tq[sl] = [q.tile_q for q in qs]
-            m, m_ct = self._dispatch_admit_tiles(ps, jnp.asarray(tq))
-            mask_ct = np.asarray(m_ct)
+            q_seg_dev = jnp.asarray(q_seg)
+            m, m_ct, counts = self._dispatch_admit_tiles(
+                ps, jnp.asarray(tq), q_seg_dev)
+            mask, counts = self._read(m, counts)
         else:
             m = self._dispatch_admit(ps)
-        mask = np.asarray(m)                                         # (N, C)
+            mask = self._read(m)                                     # (N, C)
         cams_by_q = [np.flatnonzero(mask[sl[i]]) for i in range(len(qs))]
         want_count: dict[tuple[int, int], int] = {}
         for i, q in enumerate(qs):
             for cam in cams_by_q[i]:
                 key = (int(cam), q.f_curr)
                 want_count[key] = want_count.get(key, 0) + 1
-        seg_of_frame = {f: s for s, f in
-                        enumerate(sorted({q.f_curr for q in qs}))}
-        q_seg = np.full(mask.shape[0], -1, np.int32)
-        for i, q in enumerate(qs):
-            q_seg[sl[i]] = seg_of_frame[q.f_curr]
         return RoundPlan(qs=qs, ps=ps, slots=sl, mask=mask, mask_dev=m,
                          admitted=int(mask[sl].sum()), cams_by_q=cams_by_q,
                          work=sorted(want_count), want_count=want_count,
                          seg_of_frame=seg_of_frame, q_seg=q_seg,
-                         mask_ct=mask_ct, mask_ct_dev=m_ct)
+                         mask_ct_dev=m_ct, q_seg_dev=q_seg_dev,
+                         admitted_tiles=int(counts[0]),
+                         unique_tiles=int(counts[1]))
 
     def _account_round(self, plan: RoundPlan) -> None:
         """Per-round accounting hook over the shared ``RoundPlan`` —
@@ -965,25 +993,10 @@ class ServingEngine:
             # would charge T*T per admitted step); unique_tiles is the
             # per-key UNION of admitted tiles (the deduplicated sub-frame
             # pixel-load proxy — camera-granular loads T*T per unique key)
-            TT = self.tile_grid * self.tile_grid
-            # padding rows are done and admit nothing: the whole mask's
-            # count is the live rows' count
-            adm_tiles = int(plan.mask_ct.sum())
-            stats["admitted_tiles"] += adm_tiles
-            self.admitted_tiles += adm_tiles
-            tiles_by_key: dict[tuple[int, int], np.ndarray] = {}
-            for i, q in enumerate(qs):
-                row = plan.mask_ct[sl[i]]
-                for cam in plan.cams_by_q[i]:
-                    key = (int(cam), q.f_curr)
-                    seg = row[key[0] * TT:(key[0] + 1) * TT]
-                    if key in tiles_by_key:
-                        tiles_by_key[key] |= seg
-                    else:
-                        tiles_by_key[key] = seg.copy()
-            uniq_tiles = sum(int(v.sum()) for v in tiles_by_key.values())
-            stats["unique_tiles"] += uniq_tiles
-            self.unique_tiles += uniq_tiles
+            stats["admitted_tiles"] += plan.admitted_tiles
+            self.admitted_tiles += plan.admitted_tiles
+            stats["unique_tiles"] += plan.unique_tiles
+            self.unique_tiles += plan.unique_tiles
 
         # camera-major key order (plan.work is sorted): ascending gallery
         # index reproduces the tracker's flat-argmin tie-break within every
@@ -1098,7 +1111,7 @@ class ServingEngine:
                                                 st.seg[:Gp])
                 (ps_next, m, mc, _, tv, ti, tc,
                  tf) = self._dispatch_rank_advance_tiles(
-                    ps, jnp.asarray(q_feat), jnp.asarray(plan.q_seg),
+                    ps, jnp.asarray(q_feat), plan.q_seg_dev,
                     plan.mask_ct_dev, jnp.asarray(gal),
                     jnp.asarray(gal_ct), jnp.asarray(gal_cam),
                     jnp.asarray(gal_frame), jnp.asarray(gal_seg))
@@ -1122,12 +1135,12 @@ class ServingEngine:
                     jnp.asarray(gal_frame))
             # the step's match_emb stays on the device: each matched row
             # is already in the host gallery, at the index the step chose
-            matched = np.asarray(m)
-            match_cam = np.asarray(mc)
-            topk_val = np.asarray(tv)
-            topk_idx = np.asarray(ti)
-            topk_cam = np.asarray(tc)
-            topk_frame = np.asarray(tf)
+            matched = self._read(m)
+            match_cam = self._read(mc)
+            topk_val = self._read(tv)
+            topk_idx = self._read(ti)
+            topk_cam = self._read(tc)
+            topk_frame = self._read(tf)
             emb_rows = match_rows(matched, match_cam, topk_idx, topk_cam,
                                   self.cfg.topk_rerank)
             stats["matches"] += int(matched[sl].sum())
@@ -1189,7 +1202,7 @@ class ServingEngine:
             return
         ps = self._gather(live)
         sl = self._slots
-        mask = np.asarray(self._dispatch_admit(ps))
+        mask = self._read(self._dispatch_admit(ps))
         keys: set[tuple[int, int]] = set()
         for i, q in enumerate(live):
             for cam in np.flatnonzero(mask[sl[i]]):

@@ -46,10 +46,11 @@ placement grouping and ``rex.fleet.dispatch`` around each step call):
     admit dispatch re-broadcasts, uncounted;
   * the state columns, the query features and segment ids go SHARDED: each
     worker receives its block of rows;
-  * the sharded outputs the host reads back (admission masks, match and
-    top-k columns, the advanced state but its ``live_f``;
+  * the sharded outputs the host reads back (the camera admission mask,
+    match and top-k columns, the advanced state but its ``live_f``;
     ``sharded_readback_bytes``) come one block from each worker; the
-    matched embeddings stay on the devices;
+    matched embeddings and the tile round's fused (camera, tile) mask stay
+    on the devices, and the tile counts come back as two replicated ints;
   * the embedding plane's own transfers are the gallery store's
     (``ShardedGalleryStore``: a block put on its owner's chip for every
     fresh embedding, a blocking read back for every cache hit).
@@ -65,11 +66,12 @@ from __future__ import annotations
 from typing import Iterable
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.policy import admit, admit_tiles
+from repro.core.policy import admit, admit_tiles, tile_counts
 from repro.runtime.cluster import ElasticMesh, HeartbeatMonitor
 from repro.runtime.engine import (EngineConfig, QueryState, RoundPlan,
                                   ServingEngine, _pow2, advance_round,
@@ -91,7 +93,10 @@ def make_sharded_step_fns(mesh, policy, topk: int, topk_rerank: bool = False,
     sharding alongside the state rows and the gallery's segment tags
     replicated like its cam/frame tags; the tile pair refines camera
     admission to fused (camera, tile) cells — the (Q, C*T*T) mask shards
-    with the state rows, the gallery's cell tags replicate.
+    with the state rows, the gallery's cell tags replicate.  A segment's
+    queries may sit on several shards, so the tile admit gathers the mask
+    and segment ids over the data axis and every chip counts the round's
+    tiles whole, returned replicated.
     Module-level (not a method) so the static invariant plane
     (``repro.analysis``) can trace and audit the EXACT jaxprs the fleet
     dispatches, on any mesh."""
@@ -100,8 +105,12 @@ def make_sharded_step_fns(mesh, policy, topk: int, topk_rerank: bool = False,
     def _admit(model, state, geo_adj):
         return admit(model, policy, state, geo_adj)
 
-    def _admit_tiles(model, state, geo_adj, tile_q):
-        return admit_tiles(model, policy, state, geo_adj, tile_q)
+    def _admit_tiles(model, state, geo_adj, tile_q, q_seg):
+        mask, mask_ct = admit_tiles(model, policy, state, geo_adj, tile_q)
+        counts = tile_counts(
+            jax.lax.all_gather(mask_ct, "data", tiled=True),
+            jax.lax.all_gather(q_seg, "data", tiled=True))
+        return mask, mask_ct, jnp.stack(counts)
 
     def _rank_advance(windows, state, q_feat, mask, gal, gal_cam, gal_frame):
         return rank_advance_round(policy, windows, state, q_feat, mask, gal,
@@ -139,8 +148,8 @@ def make_sharded_step_fns(mesh, policy, topk: int, topk_rerank: bool = False,
                           in_specs=(Pr, Pd), out_specs=Pd,
                           check_vma=False)),
         jax.jit(shard_map(_admit_tiles, mesh=mesh,
-                          in_specs=(Pr, Pd, Pr, Pd), out_specs=(Pd, Pd),
-                          check_vma=False)),
+                          in_specs=(Pr, Pd, Pr, Pd, Pd),
+                          out_specs=(Pd, Pd, Pr), check_vma=False)),
         jax.jit(shard_map(_rank_advance_tiles, mesh=mesh,
                           in_specs=(Pr, Pd, Pd, Pd, Pd, Pr, Pr, Pr, Pr, Pr),
                           out_specs=(Pd,) * 8,
@@ -469,11 +478,14 @@ class ShardedServingEngine(ServingEngine):
         self._read_back(m)
         return m
 
-    def _dispatch_admit_tiles(self, ps, tile_q):
+    def _dispatch_admit_tiles(self, ps, tile_q, q_seg):
         with _span("rex.fleet.dispatch"):
-            m, m_ct = self._fns()[4](self.model, ps, self._geo_adj, tile_q)
-        self._read_back(m, m_ct)
-        return m, m_ct
+            m, m_ct, counts = self._fns()[4](self.model, ps, self._geo_adj,
+                                             tile_q, q_seg)
+        # the fused mask stays on the devices for the rank step; the
+        # replicated counts are no sharded output
+        self._read_back(m)
+        return m, m_ct, counts
 
     def _dispatch_rank_advance(self, ps, q_feat, mask, gallery, gal_cam,
                                gal_frame):

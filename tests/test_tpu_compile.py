@@ -143,3 +143,51 @@ def test_fleet_rank_advance_seg_compiles_on_four_chips(topo,
     f_rank_seg = make_sharded_step_fns(mesh, policy, topk=1, n_cams=8)[2]
     text = _assert_kernel(f_rank_seg.lower(*args).compile())
     assert not [c for c in _COLLECTIVES if c in text]
+
+
+def _admit_tiles_args(state_sh, rep_sh, C=130, T=8, n=512):
+    """Abstract (model, state, geo_adj, tile_q, q_seg) for one city130_t8
+    tile round: 512 batch rows over 130 cameras' 8 x 8 tiles."""
+    import dataclasses
+    import numpy as np
+    from repro.analysis.registry import _tiny_model
+    from repro.core.policy import PhaseState, SearchPolicy
+
+    policy = SearchPolicy(scheme="rexcam", s_thresh=.05, t_thresh=.02)
+    model = dataclasses.replace(
+        _tiny_model(C=C, NB=256), tile_admit=np.ones((C, C, T * T), bool),
+        tile_grid=T, tile_learned=True)
+    model = jax.tree.map(
+        lambda a: _spec(np.shape(a), jnp.asarray(a).dtype, rep_sh), model)
+    i32 = lambda sh: _spec((n,), jnp.int32, sh)  # noqa: E731
+    state = PhaseState(f_q=i32(state_sh), c_q=i32(state_sh),
+                       f_curr=i32(state_sh), phase=i32(state_sh),
+                       live_f=_spec((n,), jnp.float32, state_sh),
+                       done=_spec((n,), jnp.bool_, state_sh))
+    return policy, (model, state, _spec((C, C), jnp.bool_, rep_sh),
+                    i32(state_sh), i32(state_sh))
+
+
+def test_admit_tiles_step_compiles(one_chip):
+    """The engine's tile admit program at city size on one chip: both
+    masks plus the round's tile counts from the segment one-hot product."""
+    from repro.runtime.engine import _admit_tiles_jit
+    policy, (model, *args) = _admit_tiles_args(one_chip, one_chip)
+    compiled = _admit_tiles_jit.lower(model, policy, *args).compile()
+    assert compiled.as_text()
+
+
+def test_fleet_admit_tiles_compiles_on_four_chips(topo):
+    """The fleet's tile admit over a 4-chip data axis: each chip gathers
+    the fused mask and segment ids to count the round's tiles whole."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.runtime.cluster import ElasticMesh
+    from repro.runtime.fleet import make_sharded_step_fns
+
+    mesh = ElasticMesh(model_parallel=1).make_mesh(topo.devices)
+    policy, args = _admit_tiles_args(NamedSharding(mesh, P("data")),
+                                     NamedSharding(mesh, P()))
+    f_admit_tiles = make_sharded_step_fns(mesh, policy, topk=1,
+                                          n_cams=130)[4]
+    text = f_admit_tiles.lower(*args).compile().as_text()
+    assert "all-gather" in text
